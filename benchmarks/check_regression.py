@@ -14,8 +14,8 @@ CI runners and the recording machine differ in absolute speed, so raw
 therefore NORMALIZED by a same-run sibling (both sides share the engine and
 the host, so machine speed cancels): the windowed pipeline by the jnp tiled
 matcher of the same graph, the locality-sharded distributed matcher by the
-dispersed jnp-local-pass distributed baseline (same forced-4-device
-subprocess), and the b-matching router by the same-run
+dispersed jnp-local-pass distributed baseline (same 4-device bench
+process), and the b-matching router by the same-run
 ``window_match/tile128`` row (both engine-bound jnp tile passes):
 
     ratio(run, row) = us(gated_row) / us(norm_row)

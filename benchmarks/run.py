@@ -23,12 +23,16 @@ def main() -> None:
                     choices=["both", "jnp", "windowed", "distributed"],
                     help="which matcher path kernel_bench times (jnp tiled, "
                          "device-resident windowed pipeline, or the "
-                         "forced-4-device distributed matcher)")
+                         "4-device distributed matcher; on CPU run with "
+                         "XLA_FLAGS=--xla_force_host_platform_device_count=4)")
     ap.add_argument("--reorder", default="degree",
                     choices=["none", "degree", "bfs", "greedy"],
                     help="locality reordering for the windowed schedule")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         table1_speedup, fig7_work, fig10_gain, table2_conflicts,
         kernel_bench, packing_bench,

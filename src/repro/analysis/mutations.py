@@ -32,7 +32,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import engine
 from repro.core.statespec import DEFAULT, StateSpec
-from repro.kernels.skipper_match.kernel import _match_tile, _one_hot
+from repro.kernels.skipper_match.kernel import (
+    _boundary_pallas_call,
+    _store_decisions,
+    pair_tile,
+    slab_shape,
+)
 
 _TILE = 256
 _WINDOW = 256
@@ -59,18 +64,9 @@ def _mutant_dropped_dma_wait(
         cp.start()
         cp.wait()
 
-    def _set_pair(value):
-        pair_ref[...] = value.reshape(2, window)
-
-    cell = engine.StateCell(
-        get=lambda: pair_ref[...].reshape(2 * window), set=_set_pair
-    )
-    matched, conflicts = _match_tile(
-        u_ref[0, :], v_ref[0, :], cell,
-        vector_rounds=vector_rounds, window=2 * window, fallback=fallback,
-    )
-    matched_ref[0, :] = matched.astype(spec.counter_dtype)
-    conflicts_ref[0, :] = conflicts.astype(spec.counter_dtype)
+    pair_tile(u_ref, v_ref, matched_ref, conflicts_ref, pair_ref,
+              vector_rounds=vector_rounds, window=window, fallback=fallback,
+              spec=spec)
 
     @pl.when(bv != bu)
     def _store_v():
@@ -103,18 +99,9 @@ def _mutant_swapped_writeback(
         cp.start()
         cp.wait()
 
-    def _set_pair(value):
-        pair_ref[...] = value.reshape(2, window)
-
-    cell = engine.StateCell(
-        get=lambda: pair_ref[...].reshape(2 * window), set=_set_pair
-    )
-    matched, conflicts = _match_tile(
-        u_ref[0, :], v_ref[0, :], cell,
-        vector_rounds=vector_rounds, window=2 * window, fallback=fallback,
-    )
-    matched_ref[0, :] = matched.astype(spec.counter_dtype)
-    conflicts_ref[0, :] = conflicts.astype(spec.counter_dtype)
+    pair_tile(u_ref, v_ref, matched_ref, conflicts_ref, pair_ref,
+              vector_rounds=vector_rounds, window=window, fallback=fallback,
+              spec=spec)
 
     # MUTATION: u row stored FIRST, v row last (and conditionally) — a
     # same-block pair's only meaningful row no longer wins unconditionally.
@@ -150,26 +137,25 @@ def _mutant_dynamic_gather(
         cp.start()
         cp.wait()
 
-    u = u_ref[0, :]
-    v = v_ref[0, :]
+    u = u_ref[...].reshape(-1)
+    v = v_ref[...].reshape(-1)
     valid = (u >= 0) & (u != v)
-    flat = pair_ref[...].reshape(2 * window)
+    flat = jnp.concatenate([pair_ref[0].reshape(-1), pair_ref[1].reshape(-1)])
     # MUTATION: data-dependent vector gather (jaxpr `gather` with a traced
-    # index operand) instead of one_hot(u) @ state.
+    # index operand) instead of the one-hot matmul gather.
     su = flat[jnp.where(valid, u, 0)]
     sv = flat[jnp.where(valid, v, 0)]
     matched = valid & (su == 0) & (sv == 0)
 
-    hu = _one_hot(jnp.where(matched, u, -1), 2 * window)
-    hv = _one_hot(jnp.where(matched, v, -1), 2 * window)
-    ci = matched.astype(jnp.int32)
-    hit = (ci @ hu) + (ci @ hv)
-    pair_ref[...] = jnp.where(
-        hit > 0, engine.MCHD, flat
-    ).astype(spec.vmem_dtype).reshape(2, window)
+    hit = jnp.zeros(flat.shape, jnp.int32)
+    hit = hit.at[jnp.where(matched, u, 0)].max(matched.astype(jnp.int32))
+    hit = hit.at[jnp.where(matched, v, 0)].max(matched.astype(jnp.int32))
+    flat = jnp.where(hit > 0, engine.MCHD, flat).astype(spec.vmem_dtype)
+    pair_ref[0] = flat[:window].reshape(pair_ref.shape[1:])
+    pair_ref[1] = flat[window:].reshape(pair_ref.shape[1:])
 
-    matched_ref[0, :] = matched.astype(spec.counter_dtype)
-    conflicts_ref[0, :] = jnp.zeros_like(u).astype(spec.counter_dtype)
+    _store_decisions(matched_ref, conflicts_ref, matched[:, None],
+                     jnp.zeros((u.shape[0], 1), jnp.int32), spec)
 
     @pl.when(bv != bu)
     def _store_v():
@@ -197,47 +183,21 @@ def make_state(num_vertices):
 
 
 def _build_mutant_call(kernel_fn, spec: StateSpec = DEFAULT):
-    """Wrap a mutant kernel in the production boundary grid spec (verbatim
-    copy of ``build_boundary_matcher``'s spec at the canonical shapes)."""
+    """Wrap a mutant kernel in the production boundary grid spec
+    (``kernel._boundary_pallas_call``) at the canonical shapes."""
     num_tiles, tile_size = 2, _TILE
     num_windows, window = _NUM_WINDOWS, _WINDOW
     spec.validate_rounds(1)
     kernel = functools.partial(
         kernel_fn, vector_rounds=1, window=window, fallback=True, spec=spec
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, tile_size), lambda i, bu, bv: (i, 0)),
-            pl.BlockSpec((1, tile_size), lambda i, bu, bv: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec((1, tile_size), lambda i, bu, bv: (i, 0)),
-            pl.BlockSpec((1, tile_size), lambda i, bu, bv: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, window), spec.vmem_dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((num_windows, window), spec.vmem_dtype),
-            jax.ShapeDtypeStruct((num_tiles, tile_size), spec.counter_dtype),
-            jax.ShapeDtypeStruct((num_tiles, tile_size), spec.counter_dtype),
-        ],
-        input_output_aliases={4: 0},
-        interpret=True,
+    tile, srow = slab_shape(tile_size), slab_shape(window)
+    call = _boundary_pallas_call(
+        kernel, num_tiles, tile, num_windows, srow, True, spec
     )
     blk = jax.ShapeDtypeStruct((num_tiles,), jnp.int32)
-    uv = jax.ShapeDtypeStruct((num_tiles, tile_size), jnp.int32)
-    st = jax.ShapeDtypeStruct((num_windows, window), spec.vmem_dtype)
+    uv = jax.ShapeDtypeStruct((num_tiles,) + tile, jnp.int32)
+    st = jax.ShapeDtypeStruct((num_windows,) + srow, spec.vmem_dtype)
     return jax.make_jaxpr(call)(blk, blk, uv, uv, st)
 
 

@@ -14,7 +14,8 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core as jax_core
+from jax.experimental import pallas as pl
+from jax.extend import core as jax_core
 
 
 # --------------------------------------------------------------------------
@@ -97,8 +98,8 @@ class KernelOperand:
         if self.block_mapping is None:
             return None
         return tuple(
-            int(b) for b in self.block_mapping.block_shape
-            if not _is_squeezed(b)
+            int(getattr(b, "block_size", b))
+            for b in self.block_mapping.block_shape if not _is_squeezed(b)
         ) or (1,)
 
     @property
@@ -107,8 +108,12 @@ class KernelOperand:
 
 
 def _is_squeezed(dim) -> bool:
-    # pallas marks BlockSpec dims mapped with pl.squeezed / None; keep ints
-    return not isinstance(dim, (int, np.integer))
+    # pallas marks BlockSpec dims mapped with None as pl.Squeezed
+    return dim is None or isinstance(dim, pl.Squeezed)
+
+
+def _is_ref(v) -> bool:
+    return isinstance(getattr(v, "aval", None), jax.ref.AbstractRef)
 
 
 @dataclasses.dataclass
@@ -135,11 +140,9 @@ class DmaEvent:
 
 def _dma_refs(eqn):
     """Split a dma eqn's invars into (src ref, dst ref, sem ref, index
-    vars). Layout (jax 0.4.x): [src, *src_idx, dst, *dst_idx, sem, ...] —
+    vars). Layout: [src, *src_idx, dst, *dst_idx, sem, ...] —
     refs are the invars with ref avals, in order src, dst, sem."""
-    refs = [v for v in eqn.invars
-            if hasattr(getattr(v, "aval", None), "memory_space")
-            or "MemRef" in str(getattr(v, "aval", ""))]
+    refs = [v for v in eqn.invars if _is_ref(v)]
     idx = [
         v for v in eqn.invars
         if v not in refs and isinstance(v, jax_core.Var)
@@ -322,7 +325,7 @@ def eval_index_map(block_mapping, grid_point: Sequence[int]):
     if n_extra < 0:
         return None
     try:
-        out = jax_core.eval_jaxpr(cj.jaxpr, cj.consts, *args)
+        out = jax_core.jaxpr_as_fun(cj)(*args)
     except Exception:
         return None
     return tuple(int(x) for x in out)
@@ -360,10 +363,6 @@ def peak_live_bytes(jaxpr) -> int:
         if isinstance(v, jax_core.Var):
             last_use[id(v)] = len(eqns)
 
-    def is_ref(v) -> bool:
-        return hasattr(getattr(v, "aval", None), "memory_space") or \
-            "MemRef" in str(getattr(v, "aval", ""))
-
     live: Dict[int, int] = {}
     cur = 0
     peak = 0
@@ -373,7 +372,7 @@ def peak_live_bytes(jaxpr) -> int:
             sub_peak = max(sub_peak, peak_live_bytes(sub))
         peak = max(peak, cur + sub_peak)
         for v in eqn.outvars:
-            if isinstance(v, jax_core.Var) and not is_ref(v):
+            if isinstance(v, jax_core.Var) and not _is_ref(v):
                 b = _aval_bytes(v.aval)
                 if b and last_use.get(id(v), -1) > i:
                     live[id(v)] = b

@@ -1,121 +1,55 @@
-"""Version-compat shims for jax API drift.
+"""One place for the mesh / sharding calls the codebase shares.
 
-The sharding / launch / roofline layers were written against the
-``jax.sharding.AxisType`` era (jax >= 0.5); the container ships jax 0.4.37,
-which predates ``AxisType``, ``jax.set_mesh``, ``jax.sharding.
-get_abstract_mesh``, the ``(shape, names, axis_types=...)`` ``AbstractMesh``
-constructor, and returns ``Compiled.cost_analysis()`` as a one-element list.
-Every such call site routes through this module so the rest of the codebase
-is written once, against the modern surface (ROADMAP "jax version drift").
-
-All shims degrade to the semantically-equivalent legacy API; none of them
-changes behaviour on modern jax.
+Written for the installed jax (>= 0.9): meshes carry explicit ``Auto``
+axis types, the ambient mesh is ``jax.set_mesh``, and
+``Compiled.cost_analysis()`` is a dict. Call sites route through here so
+the next API change is repaired in one file.
 """
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Dict, Sequence, Tuple
+from typing import Any, Dict, Sequence
 
 import jax
 
 
-def _parse_version(s: str) -> Tuple[int, ...]:
-    parts = []
-    for p in s.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: Tuple[int, ...] = _parse_version(jax.__version__)
-
-# The single capability probe the mesh shims branch on: AxisType arrived
-# together with the explicit-sharding mesh API.
-HAS_AXIS_TYPE: bool = hasattr(jax.sharding, "AxisType")
-
-
-def jax_at_least(*version: int) -> bool:
-    """True iff the runtime jax is at least ``version`` (e.g. (0, 5))."""
-    return JAX_VERSION >= tuple(version)
-
-
 def auto_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` on modern jax, ``None`` where it predates
-    AxisType (legacy meshes are implicitly fully automatic)."""
-    if HAS_AXIS_TYPE:
-        return (jax.sharding.AxisType.Auto,) * n
-    return None
+    """``(AxisType.Auto,) * n``."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_mesh(shape: Sequence[int], names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types when the kwarg exists."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            tuple(shape), tuple(names), axis_types=auto_axis_types(len(names))
-        )
-    return jax.make_mesh(tuple(shape), tuple(names))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(shape), tuple(names), axis_types=auto_axis_types(len(names))
+    )
 
 
 def abstract_mesh(shape: Sequence[int], names: Sequence[str]):
-    """Device-free mesh for shape-only sharding computations.
-
-    Modern jax: ``AbstractMesh(shape, names, axis_types=...)``. jax 0.4.x
-    takes a single ``((name, size), ...)`` tuple and no axis types.
-    """
-    if HAS_AXIS_TYPE:
-        return jax.sharding.AbstractMesh(
-            tuple(shape), tuple(names), axis_types=auto_axis_types(len(names))
-        )
-    return jax.sharding.AbstractMesh(tuple(zip(names, shape)))
+    """Device-free mesh for shape-only sharding computations."""
+    return jax.sharding.AbstractMesh(
+        tuple(shape), tuple(names), axis_types=auto_axis_types(len(names))
+    )
 
 
 def set_mesh(mesh) -> contextlib.AbstractContextManager:
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` on modern jax; on legacy jax a concrete ``Mesh`` is
-    itself a context manager that installs the thread-local physical mesh,
-    which ``get_abstract_mesh`` below reads back.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def get_abstract_mesh():
-    """The mesh currently in scope (or an empty mesh when none is).
-
-    Modern jax: ``jax.sharding.get_abstract_mesh``. Legacy jax: the
-    thread-local physical mesh installed by ``with mesh:`` /
-    :func:`set_mesh`. Both expose ``.empty``, ``.axis_names`` and ``.shape``,
-    which is all callers use.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    return jax.interpreters.pxla.thread_resources.env.physical_mesh
+    """The mesh currently in scope (an empty mesh when none is)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` (keyword mesh, ``check_vma``) on modern jax;
-    ``jax.experimental.shard_map.shard_map`` (``check_rep``) on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map(
-        f, mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
+    """``jax.shard_map`` with a keyword mesh."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
     )
 
 
 def cost_analysis(compiled) -> Dict[str, Any]:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version
-    (0.4.x returns a one-element list of dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca
+    """``Compiled.cost_analysis()`` as a flat dict."""
+    return compiled.cost_analysis() or {}

@@ -102,9 +102,9 @@ class StateCell:
     Backed either by a plain value (``StateCell(value)`` — the jnp tile
     passes thread jax arrays / pytrees through it) or by caller get/set
     closures (``StateCell(get=..., set=...)`` — the Pallas kernels' views
-    over VMEM refs, e.g. the (1, W) pipeline block or the (2, W) pair
-    scratch). Only whole-cell ``cell[...]`` reads/writes are supported; the
-    index is ignored.
+    over VMEM refs, e.g. the pipeline's state slab or the boundary
+    kernel's two pair slabs). Only whole-cell ``cell[...]`` reads/writes
+    are supported; the index is ignored.
     """
 
     __slots__ = ("_get", "_set", "value")
@@ -128,25 +128,27 @@ class StateCell:
         self._set(value)
 
 
-def share_matrix(u: jax.Array, v: jax.Array, valid: jax.Array) -> jax.Array:
+def share_matrix(u: jax.Array, v: jax.Array, valid: jax.Array,
+                 rows=None) -> jax.Array:
     """conflict[i, j] = True iff j < i, both valid, and edges i, j share an
     endpoint. TPU-safe: strictly-lower-triangular mask via 2-D iota (Pallas
     TPU requires >= 2-D iota; XLA lowers it identically).
 
-    Args: u/v int32[T] endpoint ids, valid bool[T]. Returns bool[T, T].
-    This is the JIT-conflict matrix of DESIGN.md §2 level 0; build it once
-    per tile — it is free-mask independent and reused by every round."""
+    Args: u/v int32[T] endpoint ids, valid bool[T] — or, inside a Pallas
+    kernel, the [T, 1] columns with ``rows=(u, v, valid)`` their [1, T]
+    rows (Mosaic cannot relayout a 1-D vector into either). Returns
+    bool[T, T]. This is the JIT-conflict matrix of DESIGN.md §2 level 0;
+    build it once per tile — it is free-mask independent and reused by
+    every round."""
+    if rows is None:
+        rows = (u[None, :], v[None, :], valid[None, :])
+        u, v, valid = u[:, None], v[:, None], valid[:, None]
+    ur, vr, valid_r = rows
     t = u.shape[0]
-    share = (
-        (u[:, None] == u[None, :])
-        | (u[:, None] == v[None, :])
-        | (v[:, None] == u[None, :])
-        | (v[:, None] == v[None, :])
-    )
-    rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
-    lower = cols < rows
-    return share & lower & valid[None, :] & valid[:, None]
+    share = (u == ur) | (u == vr) | (v == ur) | (v == vr)
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    col_id = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    return share & (col_id < row_id) & valid_r & valid
 
 
 def blocked_from_matrix(conflict: jax.Array) -> Callable[[jax.Array], jax.Array]:
@@ -522,7 +524,8 @@ def run_first_claim_rounds(
             :func:`first_k_claim_commit`.
 
     Returns:
-        ``(matched bool[T], conflicts int32[T])`` — commits accumulated over
+        ``(matched bool, conflicts int32)``, shaped like ``valid`` ([T], or
+        the Pallas kernels' [T, 1] columns) — commits accumulated over
         the rounds and the per-edge blocked-round count (Table II
         instrumentation).
 
@@ -531,7 +534,6 @@ def run_first_claim_rounds(
     had remaining room. The lowest-index free edge always commits, so every
     round makes progress.
     """
-    t = u.shape[0]
     if capacities is None:
         if blocked_fn is None:
             blocked_fn = blocked_from_matrix(share_matrix(u, v, valid))
@@ -550,8 +552,8 @@ def run_first_claim_rounds(
                 a, b, valid, matched, blocked_fn, cap_u, cap_v
             )
 
-    matched = jnp.zeros((t,), jnp.bool_)
-    conflicts = jnp.zeros((t,), jnp.int32)
+    matched = jnp.zeros(valid.shape, jnp.bool_)
+    conflicts = jnp.zeros(valid.shape, jnp.int32)
     for _ in range(vector_rounds):
         a, b = read_state()
         commit, blocked = commit_round(a, b, matched)
@@ -596,7 +598,10 @@ def greedy_fallback_rounds(
     pair (any pytree) when ``capacities=(cap_u, cap_v)`` is given.
     ``gather``/``scatter`` are *pure value* functions (state in, state out) so
     the state threads through the ``while_loop`` carry explicitly — closures
-    that mutate a cell would leak tracers across the loop boundary. The
+    that mutate a cell would leak tracers across the loop boundary. (The
+    Pallas kernels pass ``state=()`` and close over their VMEM refs: a ref
+    write is an effect, not a tracer, and Mosaic cannot carry packed uint8
+    state through a loop.) The
     gathered per-edge values ride the carry too: one gather per iteration (in
     the kernel a gather is two [T, W] MXU matmuls — don't pay it twice).
     """
@@ -622,20 +627,23 @@ def greedy_fallback_rounds(
         return carry[2]
 
     def body(carry):
-        state, matched, _, a, b = carry
+        state, m, _, a, b = carry
+        matched = m > 0
         commit, _blocked = commit_round(a, b, matched)
         state = scatter(state, commit)
         matched = matched | commit
         a, b = gather(state)
         go = jnp.any(free_mask(a, b, matched))
-        return state, matched, go, a, b
+        m = matched.astype(jnp.int32)
+        return state, m, go, a, b
 
     a, b = gather(state)
     taken = jnp.any(free_mask(a, b, matched))
-    state, matched, _, _, _ = jax.lax.while_loop(
-        cond, body, (state, matched, taken, a, b)
-    )
-    return state, matched, taken
+    # matched rides the carry as int32: Mosaic cannot carry a bool vector
+    # through a loop
+    m = matched.astype(jnp.int32)
+    state, m, _, _, _ = jax.lax.while_loop(cond, body, (state, m, taken, a, b))
+    return state, m > 0, taken
 
 
 def tile_pass(

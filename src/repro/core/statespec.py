@@ -16,7 +16,7 @@ field                 governs
 ``at_rest``           HBM / returned vertex-state arrays (``MatchResult``,
                       residual-replay rebuilds, ``skipper()`` init state)
 ``vmem``              kernel-tier working state: Pallas VMEM window blocks,
-                      the boundary kernel's ANY-memory state + (2, W) pair
+                      the boundary kernel's ANY-memory state + pair
                       scratch, and the XLA twin's scan carry
 ``wire``              distributed state-assembly payload (the O(V)
                       cross-device combine in the sharded matcher)

@@ -138,11 +138,16 @@ def run_subprocess(script: str, num_devices: int, timeout: int = 900):
     """Run ``script`` in a fresh interpreter with
     ``--xla_force_host_platform_device_count=num_devices`` (the main pytest
     process keeps its single-device jax). The script must print
-    ``SUBPROCESS_OK`` on success."""
+    ``SUBPROCESS_OK`` on success.
+
+    Forced host devices are CPU devices, so the child is pinned to the CPU
+    platform: it never asks for an accelerator, which belongs to one
+    process at a time and may be held by this one."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={num_devices}"
     )
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run(
         [sys.executable, "-c", script],

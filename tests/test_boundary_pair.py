@@ -147,6 +147,21 @@ def test_pair_epilogue_empty_global_tier():
     _assert_twins(g, s, "pair/empty-global")
 
 
+def test_pair_epilogue_chunked_prefetch(monkeypatch):
+    """A global tier longer than one pallas_call's scalar-prefetch capacity
+    (the block ids live in SMEM) runs as a scan of ``PREFETCH_TILES``-tile
+    calls plus a tail call over the aliased state: still bit-identical."""
+    from repro.kernels.skipper_match import kernel
+
+    monkeypatch.setattr(kernel, "PREFETCH_TILES", 4)
+    rng = np.random.default_rng(7)
+    edges = _graph(rng, 600, 3000)
+    # unique (window, tile): the cached builders are built under the patch
+    s = build_window_schedule(edges, window=112, tile_size=40)
+    assert s.num_boundary_tiles > 2 * 4 and s.num_boundary_tiles % 4
+    _assert_twins(edges, s, "pair/chunked")
+
+
 def test_pair_epilogue_single_trace():
     """The block-pair epilogue still joins the ONE compilation unit: first
     call traces the pipeline once, a repeat with the same schedule shape
