@@ -27,10 +27,12 @@ from __future__ import annotations
 from typing import Optional, Tuple, Union
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core import engine
 from repro.core.faults import (
     CORRUPT,
@@ -59,6 +61,47 @@ _PIPELINE_TRACES = 0
 
 def pipeline_trace_count() -> int:
     return _PIPELINE_TRACES
+
+
+# The pipeline's output gathers, as ``jax.named_scope``s: each HLO
+# instruction's ``op_name`` carries the one it was traced in (``op_scopes``).
+SCOPES = ("decision_gather", "conflict_gather", "state_unpermute")
+# The AOT executable of each ``_build_pipeline`` result, newest call last:
+# the calls run it, so ``op_scopes`` reads the HLO they ran.
+_COMPILED: dict = {}
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_OP_NAME = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*\bop_name="([^"]*)"',
+                      re.M)
+
+
+def op_scopes() -> Tuple[Optional[str], dict]:
+    """``(module, {instruction: scope})`` of the executable that the newest
+    ``skipper_match`` call ran: its HLO module name, and for each of its
+    instructions traced in one of ``SCOPES``, that scope. A device trace
+    names an op by its bare instruction name, which another module may
+    also use: only the ops of ``module`` are the pipeline's. ``(None, {})``
+    before the first call."""
+    if not _COMPILED:
+        return None, {}
+    text = next(reversed(_COMPILED.values())).as_text()
+    out = {}
+    for name, path in _OP_NAME.findall(text):
+        inner = [part for part in path.split("/") if part in SCOPES]
+        if inner:
+            out[name] = inner[-1]
+    return _MODULE.match(text).group(1), out
+
+
+def _executable(fn, args):
+    """``fn`` compiled ahead of time for ``args``, once per ``fn``: every
+    argument's shape is fixed by ``_build_pipeline``'s key."""
+    exe = _COMPILED.pop(fn, None)
+    if exe is None:
+        exe = fn.lower(*args).compile()
+    _COMPILED[fn] = exe
+    while len(_COMPILED) > 64:
+        del _COMPILED[next(iter(_COMPILED))]
+    return exe
 
 
 def _auto_interpret() -> bool:
@@ -127,6 +170,8 @@ def _build_pipeline(
     the SAME stream positions / state cells (DESIGN.md §11): drop global-tier
     slots before the epilogue, lose one window row's tier contribution,
     corrupt assembled-state bytes.
+
+    The output gathers run in the named scopes of ``SCOPES``.
     """
     n_flat = num_windows * window
     nb_tiles = num_boundary_padded // tile_size
@@ -230,29 +275,33 @@ def _build_pipeline(
         # A gather, not a scatter — a |E|-index scatter costs ~100x more on
         # CPU XLA and the map is static per schedule.
         cdt = spec.counter_dtype
-        dec = [matched2.reshape(-1)]
-        cfs = [conf2.reshape(-1)]
-        if nb_tiles:
-            dec.append(bmt.reshape(-1).astype(cdt))
-            cfs.append(bcf.reshape(-1).astype(cdt))
-        dec.append(jnp.zeros((1,), cdt))
-        cfs.append(jnp.zeros((1,), cdt))
-        mask = jnp.concatenate(dec)[src] > 0
+        with jax.named_scope("decision_gather"):
+            dec = [matched2.reshape(-1)]
+            if nb_tiles:
+                dec.append(bmt.reshape(-1).astype(cdt))
+            dec.append(jnp.zeros((1,), cdt))
+            mask = jnp.concatenate(dec)[src] > 0
         # per-edge conflicts stay i32 at the public boundary (callers sum
         # them into Counters); the narrow width is the O(E) buffer inside
-        conf = jnp.concatenate(cfs)[src].astype(jnp.int32)
+        with jax.named_scope("conflict_gather"):
+            cfs = [conf2.reshape(-1)]
+            if nb_tiles:
+                cfs.append(bcf.reshape(-1).astype(cdt))
+            cfs.append(jnp.zeros((1,), cdt))
+            conf = jnp.concatenate(cfs)[src].astype(jnp.int32)
 
-        nmatch = jnp.sum(mask).astype(jnp.int32)
-        nconf = jnp.sum(conf).astype(jnp.int32)
-        counters = Counters(
-            edge_reads=jnp.asarray(m, jnp.int32),
-            state_loads=jnp.asarray(2 * m, jnp.int32) + 2 * nconf,
-            state_stores=2 * nmatch,
-            rounds=jnp.asarray(1, jnp.int32),
-        )
+            nmatch = jnp.sum(mask).astype(jnp.int32)
+            nconf = jnp.sum(conf).astype(jnp.int32)
+            counters = Counters(
+                edge_reads=jnp.asarray(m, jnp.int32),
+                state_loads=jnp.asarray(2 * m, jnp.int32) + 2 * nconf,
+                state_stores=2 * nmatch,
+                rounds=jnp.asarray(1, jnp.int32),
+            )
         # back to ORIGINAL vertex ids: original vertex i lives at renumbered
         # slot perm[i] of the flattened state (perm = arange when unordered).
-        state_out = flat.reshape(n_flat)[perm].astype(spec.at_rest_dtype)
+        with jax.named_scope("state_unpermute"):
+            state_out = flat.reshape(n_flat)[perm].astype(spec.at_rest_dtype)
         return mask, state_out, conf, counters
 
     return jax.jit(pipeline)
@@ -277,6 +326,11 @@ def skipper_match(
 ) -> Union[MatchResult, Tuple]:
     """Full-graph device-resident matcher: one traced pipeline for all
     windows plus the in-device boundary epilogue.
+
+    The call copies the schedule to the device and waits until the copy
+    has landed (span ``match.to_device``, counter ``match.h2d_bytes``),
+    then dispatches the pipeline, which runs asynchronously: a caller
+    cannot overlap host work with the copy.
 
     Pass ``schedule`` (from ``build_window_schedule``) to skip the host
     precompute — e.g. when timing the compiled device path; ``window`` /
@@ -355,17 +409,17 @@ def skipper_match(
     perm = schedule.perm
     if perm is None:
         perm = jnp.arange(schedule.num_vertices, dtype=jnp.int32)
-    mask, state, conflicts, counters = fn(
-        jnp.asarray(schedule.u_tiles),
-        jnp.asarray(schedule.v_tiles),
-        jnp.asarray(schedule.stream_src),
-        jnp.asarray(schedule.boundary_blk_u),
-        jnp.asarray(schedule.boundary_blk_v),
-        jnp.asarray(schedule.boundary_ulocal),
-        jnp.asarray(schedule.boundary_vlocal),
-        jnp.asarray(schedule.window_ids),
-        jnp.asarray(perm),
-    )
+    host = (schedule.u_tiles, schedule.v_tiles, schedule.stream_src,
+            schedule.boundary_blk_u, schedule.boundary_blk_v,
+            schedule.boundary_ulocal, schedule.boundary_vlocal,
+            schedule.window_ids, perm)
+    # The schedule copy ends when the arrays have landed; the pipeline
+    # could not start before that anyway.
+    with spans.span("match.to_device"):
+        args = jax.block_until_ready(jax.device_put(host))
+    spans.count("match.h2d_bytes", sum(
+        d.nbytes for h, d in zip(host, args) if not isinstance(h, jax.Array)))
+    mask, state, conflicts, counters = _executable(fn, args)(*args)
     result = MatchResult(match_mask=mask, state=state, counters=counters)
 
     report = None
