@@ -13,8 +13,10 @@
     touches; ``engine.tile_pass_pair`` scan on the xla twin) that resolves
     the block-pair grouped global tier (cross-window + coalesced
     sparse-window edges). Every edge is still decided exactly once;
-    Counters are computed on device; mask/conflicts/state come back in
-    original stream order / vertex ids even when the schedule is reordered.
+    Counters are summed on device over the slot-order decisions;
+    mask/state (and the per-edge conflicts, gathered only when a caller
+    asks ``with_conflicts=True``) come back in original stream order /
+    vertex ids even when the schedule is reordered.
 
 ``interpret`` is a debug flag: ``None`` (default) resolves to False on TPU
 (compiled Mosaic) and True elsewhere (Pallas' interpreter is the only Pallas
@@ -156,6 +158,7 @@ def _build_pipeline(
     conflict_method: str,
     faults: Optional[FaultPlan] = None,
     spec: StateSpec = DEFAULT,
+    with_conflicts: bool = False,
 ):
     """One jitted compilation unit per static schedule shape: windowed kernel
     sweep over the dense rows + boundary epilogue + on-device counters.
@@ -171,7 +174,10 @@ def _build_pipeline(
     slots before the epilogue, lose one window row's tier contribution,
     corrupt assembled-state bytes.
 
-    The output gathers run in the named scopes of ``SCOPES``.
+    The output gathers run in the named scopes of ``SCOPES``. The per-edge
+    conflicts are gathered to stream order only under ``with_conflicts``
+    (the jit then returns them, else None); ``Counters`` are summed over
+    the slot-order buffers either way, in the ``conflict_gather`` scope.
     """
     n_flat = num_windows * window
     nb_tiles = num_boundary_padded // tile_size
@@ -281,17 +287,24 @@ def _build_pipeline(
                 dec.append(bmt.reshape(-1).astype(cdt))
             dec.append(jnp.zeros((1,), cdt))
             mask = jnp.concatenate(dec)[src] > 0
-        # per-edge conflicts stay i32 at the public boundary (callers sum
-        # them into Counters); the narrow width is the O(E) buffer inside
         with jax.named_scope("conflict_gather"):
-            cfs = [conf2.reshape(-1)]
+            # Every edge is decided in exactly one slot, and a slot that no
+            # edge holds (padding, dropped) counts no conflict: the sum over
+            # the slots is the sum over the stream.
+            nconf = jnp.sum(conf2.reshape(-1), dtype=jnp.int32)
             if nb_tiles:
-                cfs.append(bcf.reshape(-1).astype(cdt))
-            cfs.append(jnp.zeros((1,), cdt))
-            conf = jnp.concatenate(cfs)[src].astype(jnp.int32)
+                nconf = nconf + jnp.sum(bcf.reshape(-1), dtype=jnp.int32)
+            conf = None
+            if with_conflicts:
+                # per-edge conflicts stay i32 at the public boundary; the
+                # narrow width is the O(E) buffer inside
+                cfs = [conf2.reshape(-1)]
+                if nb_tiles:
+                    cfs.append(bcf.reshape(-1).astype(cdt))
+                cfs.append(jnp.zeros((1,), cdt))
+                conf = jnp.concatenate(cfs)[src].astype(jnp.int32)
 
             nmatch = jnp.sum(mask).astype(jnp.int32)
-            nconf = jnp.sum(conf).astype(jnp.int32)
             counters = Counters(
                 edge_reads=jnp.asarray(m, jnp.int32),
                 state_loads=jnp.asarray(2 * m, jnp.int32) + 2 * nconf,
@@ -338,6 +351,10 @@ def skipper_match(
     schedule. ``reorder`` selects a locality renumbering policy
     (``graphs/reorder.py``); results — mask, conflicts AND state — are
     always in the original edge-stream order / vertex ids regardless.
+    The per-edge conflicts (int32[|E|]) are gathered back to stream order
+    only under ``with_conflicts=True``, which compiles a pipeline of its
+    own; ``Counters`` are summed over the slot-order decisions on every
+    call and do not need them.
     ``conflict_method`` reaches the XLA twin's boundary-epilogue
     ``engine.tile_pass`` (the Pallas kernels force the share-matrix form —
     Mosaic has no sort/scatter); the choice never changes output.
@@ -390,6 +407,9 @@ def skipper_match(
     if interpret is None:
         interpret = _auto_interpret()
     spec = resolve_spec(spec)
+    # ``with_conflicts`` is passed only when set, so that a call without it
+    # shares the lru entry of the callers that leave it out
+    # (``analysis/targets.py``, the tests)
     fn = _build_pipeline(
         schedule.num_windows,
         schedule.num_rows,
@@ -405,6 +425,7 @@ def skipper_match(
         conflict_method,
         faults,
         spec,
+        *((True,) if with_conflicts else ()),
     )
     perm = schedule.perm
     if perm is None:
