@@ -456,7 +456,7 @@ def locality_sharded_fn(
     n_flat = num_windows * window
 
     # ---- PHASE A: device-resident window tier (no collectives) ----------
-    states, matched_w, conf_w = window_tier_pass(
+    states, matched_w, conf_w, _ = window_tier_pass(
         u_rows, v_rows,
         window=window,
         tiles_per_window=tiles_per_window,

@@ -941,7 +941,7 @@ def window_tier_pass(
     backend: str,
     interpret: bool = True,
     spec: Optional[StateSpec] = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Run the window tier of a two-tier schedule: each row is one window's
     dispersed tile stream, matched from an all-ACC window-local state
     (DESIGN.md §3; the distributed consumer is §8 step 1).
@@ -969,9 +969,10 @@ def window_tier_pass(
             graphs stay dtype-identical, not just value-identical.
 
     Returns:
-        ``(states, matched, conflicts)`` with ``states`` of shape
-        ``spec.vmem[num_rows, window]`` and ``matched``/``conflicts``
-        ``spec.counter`` of ``u_rows``'s shape (values identical across
+        ``(states, matched, conflicts, fallback_tiles)`` with ``states`` of
+        shape ``spec.vmem[num_rows, window]``, ``matched``/``conflicts``
+        ``spec.counter`` of ``u_rows``'s shape, and the int32 count of
+        tiles that took the exact fallback (values identical across
         backends and specs, test-pinned).
 
     Invariant: each row's result depends only on that row's tiles (windows
@@ -988,12 +989,12 @@ def window_tier_pass(
             vector_rounds, True, interpret, spec,
         )
         state0 = jnp.zeros((num_rows, window), spec.vmem_dtype)
-        states, matched, conflicts = call(u_rows, v_rows, state0)
+        states, matched, conflicts, taken = call(u_rows, v_rows, state0)
     elif backend == "xla":
         from repro.kernels.skipper_match.ref import make_ref_pipeline
 
         run = make_ref_pipeline(window, vector_rounds, spec=spec)
-        states, matched, conflicts = run(
+        states, matched, conflicts, taken = run(
             u_rows.reshape(num_rows, tiles_per_window, tile_size),
             v_rows.reshape(num_rows, tiles_per_window, tile_size),
         )
@@ -1001,4 +1002,4 @@ def window_tier_pass(
         conflicts = conflicts.reshape(u_rows.shape)
     else:
         raise ValueError(f"unknown backend {backend!r}")
-    return states, matched, conflicts
+    return states, matched, conflicts, taken

@@ -38,9 +38,13 @@ class Counters:
     state_loads: jax.Array      # loads of state[]
     state_stores: jax.Array     # stores to state[]
     rounds: jax.Array           # iterations / passes over (parts of) the graph
+    # window-tier tiles that took the exact fallback (the device-resident
+    # pipeline counts them; None where a matcher does not)
+    fallback_tiles: Optional[jax.Array] = None
 
     def tree_flatten(self):
-        return (self.edge_reads, self.state_loads, self.state_stores, self.rounds), None
+        return (self.edge_reads, self.state_loads, self.state_stores,
+                self.rounds, self.fallback_tiles), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):

@@ -55,6 +55,8 @@ the data movement is kernel-specific, and it is all 2-D:
   * fallback      : rare leftover chains resolved by iterated first-claim
     rounds to fixpoint (``engine.greedy_fallback_rounds`` — exactly the
     sequential greedy's result), all VPU/MXU work, in-VMEM, still same-pass.
+    The window-tier kernels count the tiles that took it in one int32 per
+    call, summed in SMEM (``_count_fallback``).
 
 States: ACC=0, MCHD=2. Every width (VMEM state, matched/conflicts outputs)
 comes from the builder's ``StateSpec`` (``core/statespec.py``); the default
@@ -203,16 +205,33 @@ def _match_tile(u_slab, v_slab, cell, *, blocks: int, window: int,
         vector_rounds, blocked_fn,
     )
 
+    taken = False
     if fallback:
         # exact vectorized cleanup of pathological chains (rare): iterated
         # first-claim rounds to fixpoint == the sequential index-order greedy
         # (engine.greedy_fallback_rounds), all VPU/MXU work — no scalar loop.
-        _, matched, _taken = engine.greedy_fallback_rounds(
+        _, matched, taken = engine.greedy_fallback_rounds(
             (), u, v, valid, matched, blocked_fn,
             gather=gather, scatter=scatter,
         )
 
-    return matched, conflicts
+    return matched, conflicts, taken
+
+
+def _count_fallback(count_ref, acc_ref, sem, first, last, taken):
+    """Add the tile's ``taken`` to the call's fallback count: an int32 in
+    SMEM scratch (``acc_ref``) that the first grid step starts from 0 and
+    the last copies to ``count_ref`` in HBM. An output block instead costs
+    every grid step the pipeline's bookkeeping: 2% of the kernel's time on
+    the v5e, against 0.1% for this."""
+    acc_ref[0] = jnp.where(first, 0, acc_ref[0]) + jnp.asarray(taken,
+                                                               jnp.int32)
+
+    @pl.when(last)
+    def _copy_out():
+        cp = pltpu.make_async_copy(acc_ref, count_ref, sem)
+        cp.start()
+        cp.wait()
 
 
 def _store_decisions(matched_ref, conflicts_ref, matched, conflicts, spec):
@@ -239,6 +258,9 @@ def skipper_window_kernel(
     state_ref,
     matched_ref,
     conflicts_ref,
+    fallback_ref,
+    count_ref,
+    count_sem,
     *,
     vector_rounds: int,
     window: int,
@@ -254,6 +276,8 @@ def skipper_window_kernel(
     conflicts_ref: spec.counter slab, rounds spent blocked (Table II
     instrumentation; conflicts <= vector_rounds, so the narrow store is
     exact — guarded by ``spec.validate_rounds`` at build time).
+    fallback_ref: int32[1] in HBM, the tiles that took the fallback,
+    counted in the SMEM scratch ``count_ref`` (``_count_fallback``).
     """
     step = pl.program_id(0)
 
@@ -261,11 +285,13 @@ def skipper_window_kernel(
     def _init():
         state_ref[...] = state_in_ref[...]
 
-    matched, conflicts = _match_tile(
+    matched, conflicts, taken = _match_tile(
         u_ref[...], v_ref[...], _state_cell(state_ref), blocks=1,
         window=window, vector_rounds=vector_rounds, fallback=fallback,
     )
     _store_decisions(matched_ref, conflicts_ref, matched, conflicts, spec)
+    _count_fallback(fallback_ref, count_ref, count_sem, step == 0,
+                    step == pl.num_programs(0) - 1, taken)
 
 
 def skipper_pipeline_kernel(
@@ -275,6 +301,9 @@ def skipper_pipeline_kernel(
     state_ref,
     matched_ref,
     conflicts_ref,
+    fallback_ref,
+    count_ref,
+    count_sem,
     *,
     vector_rounds: int,
     window: int,
@@ -286,18 +315,23 @@ def skipper_pipeline_kernel(
     state slab; the state block is swapped per *window*, not per step, so it
     is initialized when t == 0 and stays VMEM-resident for all tiles of w.
     The block dtype is ``spec.vmem`` — window * spec.vmem_bytes resident
-    bytes per step."""
+    bytes per step. ``fallback_ref`` (int32[1], HBM) gets the count of the
+    grid's tiles that took the fallback (``_count_fallback``)."""
+    w = pl.program_id(0)
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
         state_ref[...] = state_in_ref[...]
 
-    matched, conflicts = _match_tile(
+    matched, conflicts, taken = _match_tile(
         u_ref[...], v_ref[...], _state_cell(state_ref), blocks=1,
         window=window, vector_rounds=vector_rounds, fallback=fallback,
     )
     _store_decisions(matched_ref, conflicts_ref, matched, conflicts, spec)
+    last = (w == pl.num_programs(0) - 1) & (t == pl.num_programs(1) - 1)
+    _count_fallback(fallback_ref, count_ref, count_sem, (w == 0) & (t == 0),
+                    last, taken)
 
 
 def pair_tile(u_ref, v_ref, matched_ref, conflicts_ref, pair_ref, *,
@@ -314,7 +348,7 @@ def pair_tile(u_ref, v_ref, matched_ref, conflicts_ref, pair_ref, *,
     cell = engine.StateCell(
         get=lambda: (pair_ref[0], pair_ref[1]), set=_set_pair
     )
-    matched, conflicts = _match_tile(
+    matched, conflicts, _ = _match_tile(
         u_ref[...], v_ref[...], cell, blocks=2, window=window,
         vector_rounds=vector_rounds, fallback=fallback,
     )
@@ -525,6 +559,14 @@ def build_boundary_matcher(
     )
 
 
+# The window-tier kernels' fallback count: one int32 for the whole grid,
+# summed in SMEM scratch and copied to HBM by the last step
+# (``_count_fallback``).
+_FALLBACK_COUNT = pl.BlockSpec(memory_space=pl.ANY)
+_FALLBACK_COUNT_SHAPE = jax.ShapeDtypeStruct((1,), jnp.int32)
+_FALLBACK_SCRATCH = [pltpu.SMEM((1,), jnp.int32), pltpu.SemaphoreType.DMA]
+
+
 @functools.lru_cache(maxsize=None)
 def build_window_matcher(
     num_tiles: int,
@@ -538,8 +580,10 @@ def build_window_matcher(
     """Construct the pallas_call for a (num_tiles x tile_size) edge stream
     over a single ``window``-vertex state window. Call as ``fn(u, v,
     state0)`` with u/v int32[num_tiles * tile_size] and state0
-    spec.vmem[window]; returns (state, matched, conflicts) in the same flat
-    shapes (state in ``spec.vmem``, matched/conflicts in ``spec.counter``)."""
+    spec.vmem[window]; returns (state, matched, conflicts, fallback_tiles)
+    — the first three in the same flat shapes (state in ``spec.vmem``,
+    matched/conflicts in ``spec.counter``), then the int32 count of tiles
+    that took the exact fallback."""
     spec.validate_rounds(vector_rounds)
     kernel = functools.partial(
         skipper_window_kernel,
@@ -556,22 +600,25 @@ def build_window_matcher(
         kernel,
         grid=(num_tiles,),
         in_specs=[edges, edges, state],
-        out_specs=[state, edges, edges],
+        out_specs=[state, edges, edges, _FALLBACK_COUNT],
         out_shape=[
             jax.ShapeDtypeStruct(srow, spec.vmem_dtype),
             jax.ShapeDtypeStruct((num_tiles,) + tile, spec.counter_dtype),
             jax.ShapeDtypeStruct((num_tiles,) + tile, spec.counter_dtype),
+            _FALLBACK_COUNT_SHAPE,
         ],
+        scratch_shapes=_FALLBACK_SCRATCH,
         interpret=interpret,
         name=kernel.func.__name__,
     )
 
     def run(u, v, state0):
-        state, matched, conflicts = call(
+        state, matched, conflicts, taken = call(
             u.reshape((num_tiles,) + tile), v.reshape((num_tiles,) + tile),
             state0.reshape(srow),
         )
-        return state.reshape(window), matched.reshape(-1), conflicts.reshape(-1)
+        return (state.reshape(window), matched.reshape(-1),
+                conflicts.reshape(-1), taken[0])
 
     return run
 
@@ -592,8 +639,10 @@ def build_pipeline_matcher(
 
     Inputs: u/v int32[num_windows, tiles_per_window * tile_size] window-local
     ids, state0 spec.vmem[num_windows, window]. Outputs: (state, matched,
-    conflicts) — state in spec.vmem, matched/conflicts in spec.counter, in
-    the input shapes. The state index map ``(w, t) -> (w, 0, 0)`` ignores t:
+    conflicts, fallback_tiles) — state in spec.vmem, matched/conflicts in
+    spec.counter, in the input shapes, and the int32 count of the grid's
+    tiles that took the exact fallback. The state index map
+    ``(w, t) -> (w, 0, 0)`` ignores t:
     the revolving VMEM block is written back only when w changes — one HBM
     round-trip per window, zero host round-trips.
     """
@@ -614,18 +663,21 @@ def build_pipeline_matcher(
         kernel,
         grid=(num_windows, tiles_per_window),
         in_specs=[edges, edges, state],
-        out_specs=[state, edges, edges],   # state resident per window
+        # state resident per window
+        out_specs=[state, edges, edges, _FALLBACK_COUNT],
         out_shape=[
             jax.ShapeDtypeStruct((num_windows,) + srow, spec.vmem_dtype),
             jax.ShapeDtypeStruct(eshape, spec.counter_dtype),
             jax.ShapeDtypeStruct(eshape, spec.counter_dtype),
+            _FALLBACK_COUNT_SHAPE,
         ],
+        scratch_shapes=_FALLBACK_SCRATCH,
         interpret=interpret,
         name=kernel.func.__name__,
     )
 
     def run(u, v, state0):
-        state, matched, conflicts = call(
+        state, matched, conflicts, taken = call(
             u.reshape(eshape), v.reshape(eshape),
             state0.reshape((num_windows,) + srow),
         )
@@ -634,6 +686,7 @@ def build_pipeline_matcher(
             state.reshape(num_windows, window),
             matched.reshape(num_windows, slots),
             conflicts.reshape(num_windows, slots),
+            taken[0],
         )
 
     return run

@@ -119,10 +119,11 @@ def skipper_match_window(
     fallback: bool = True,
     interpret: Optional[bool] = None,
     spec: Optional[StateSpec] = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Match a window-local edge stream. u/v: int32[M] (padded to tile
     multiple with -1), state0: [W] (coerced to ``spec.vmem``). Returns
-    (state, matched, conflicts) in spec.vmem / spec.counter widths.
+    (state, matched, conflicts) in spec.vmem / spec.counter widths, and the
+    int32 count of tiles that took the exact fallback.
     """
     spec = resolve_spec(spec)
     if interpret is None:
@@ -138,8 +139,9 @@ def skipper_match_window(
         num_tiles, tile_size, window, vector_rounds, fallback, interpret,
         spec,
     )
-    state, matched, conflicts = call(u, v, state0.astype(spec.vmem_dtype))
-    return state, matched[:m], conflicts[:m]
+    state, matched, conflicts, taken = call(u, v,
+                                            state0.astype(spec.vmem_dtype))
+    return state, matched[:m], conflicts[:m], taken
 
 
 @functools.lru_cache(maxsize=64)
@@ -189,7 +191,7 @@ def _build_pipeline(
 
         # window tier: the engine entry point shared with the distributed
         # matcher's per-device LOCAL PASS (pallas kernel / jnp twin).
-        state2, matched2, conf2 = engine.window_tier_pass(
+        state2, matched2, conf2, wfall = engine.window_tier_pass(
             u2, v2,
             window=window,
             tiles_per_window=tiles_per_window,
@@ -310,6 +312,7 @@ def _build_pipeline(
                 state_loads=jnp.asarray(2 * m, jnp.int32) + 2 * nconf,
                 state_stores=2 * nmatch,
                 rounds=jnp.asarray(1, jnp.int32),
+                fallback_tiles=wfall,
             )
         # back to ORIGINAL vertex ids: original vertex i lives at renumbered
         # slot perm[i] of the flattened state (perm = arange when unordered).
@@ -343,7 +346,10 @@ def skipper_match(
     The call copies the schedule to the device and waits until the copy
     has landed (span ``match.to_device``, counter ``match.h2d_bytes``),
     then dispatches the pipeline, which runs asynchronously: a caller
-    cannot overlap host work with the copy.
+    cannot overlap host work with the copy. The window tier's count of
+    tiles that took the exact fallback (``Counters.fallback_tiles``) is
+    logged as the device scalar it is (counter
+    ``match.window_fallback_tiles``), so the call does not wait for it.
 
     Pass ``schedule`` (from ``build_window_schedule``) to skip the host
     precompute — e.g. when timing the compiled device path; ``window`` /
@@ -441,6 +447,7 @@ def skipper_match(
     spans.count("match.h2d_bytes", sum(
         d.nbytes for h, d in zip(host, args) if not isinstance(h, jax.Array)))
     mask, state, conflicts, counters = _executable(fn, args)(*args)
+    spans.count("match.window_fallback_tiles", counters.fallback_tiles)
     result = MatchResult(match_mask=mask, state=state, counters=counters)
 
     report = None

@@ -31,24 +31,27 @@ def ref_match_window(
     vector_rounds: int = 1,
     fallback: bool = True,
     spec: StateSpec | None = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (state, matched spec.counter[num_tiles*T], conflicts[...]).
-    ``state0``'s dtype is the caller's; matched/conflicts follow the spec
-    like ``build_window_matcher``'s outputs do."""
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Returns (state, matched spec.counter[num_tiles*T], conflicts[...],
+    fallback_tiles int32). ``state0``'s dtype is the caller's;
+    matched/conflicts follow the spec like ``build_window_matcher``'s
+    outputs do."""
     spec = resolve_spec(spec)
     w = state0.shape[0]
     cdt = spec.counter_dtype
 
     def tile_step(state, uv):
         u, v = uv
-        state, matched, conflicts, _fb = engine.tile_pass(
+        state, matched, conflicts, taken = engine.tile_pass(
             state, u, v, n=w, vector_rounds=vector_rounds, fallback=fallback,
             spec=spec,
         )
-        return state, (matched.astype(cdt), conflicts)
+        return state, (matched.astype(cdt), conflicts, taken)
 
-    state, (matched, conflicts) = jax.lax.scan(tile_step, state0, (u_tiles, v_tiles))
-    return state, matched.reshape(-1), conflicts.reshape(-1)
+    state, (matched, conflicts, taken) = jax.lax.scan(
+        tile_step, state0, (u_tiles, v_tiles))
+    return (state, matched.reshape(-1), conflicts.reshape(-1),
+            jnp.sum(taken, dtype=jnp.int32))
 
 
 def make_ref_pipeline(window: int, vector_rounds: int = 1,
@@ -76,7 +79,8 @@ def make_ref_pipeline(window: int, vector_rounds: int = 1,
     The returned callable maps (u_tiles, v_tiles)
     int32[num_rows, tiles_per_window, T] (window-local ids) to
     (state spec.vmem[num_rows, window], matched spec.counter[num_rows,
-    tpw*T], conflicts spec.counter[...]).
+    tpw*T], conflicts spec.counter[...], fallback_tiles int32): the last is
+    the count of tiles that took the exact fallback, as the kernel counts.
     """
     spec = resolve_spec(spec)
     cdt = spec.counter_dtype
@@ -91,19 +95,20 @@ def make_ref_pipeline(window: int, vector_rounds: int = 1,
         def tile_step(state, uvf):
             u, v, fr = uvf
             state = jnp.where(fr, jnp.zeros_like(state), state)
-            state, matched, conflicts, _fb = engine.tile_pass(
+            state, matched, conflicts, taken = engine.tile_pass(
                 state, u, v, n=window, vector_rounds=vector_rounds, spec=spec
             )
-            return state, (state, matched.astype(cdt), conflicts)
+            return state, (state, matched.astype(cdt), conflicts, taken)
 
         state0 = jnp.zeros((window,), spec.vmem_dtype)
-        _, (states, matched, conflicts) = jax.lax.scan(
+        _, (states, matched, conflicts, taken) = jax.lax.scan(
             tile_step, state0, (uf, vf, fresh)
         )
         return (
             states[tpw - 1 :: tpw],          # each row's final state
             matched.reshape(num_rows, tpw * t),
             conflicts.reshape(num_rows, tpw * t),
+            jnp.sum(taken, dtype=jnp.int32),
         )
 
     return run

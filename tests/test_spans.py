@@ -1,7 +1,8 @@
 """The program's own spans and counters (``repro.spans``): the bounded
-table and its interval filter, the schedule build's phase spans, the
-schedule copy's span and byte counter, the pipeline's named scopes with
-``op_scopes``, and the one executable each pipeline compiles to."""
+table and its interval filter, counts of device scalars, the schedule
+build's phase spans, the schedule copy's span and byte counter, the window
+tier's fallback count, the pipeline's named scopes with ``op_scopes``, and
+the one executable each pipeline compiles to."""
 import os
 import re
 import sys
@@ -54,6 +55,19 @@ def test_table_is_bounded_and_snapshot_filters_by_interval():
     (name, a, b), = snap.spans
     assert t0 <= a <= b <= t1
     assert [s[0] for s in spans.snapshot(t1).spans] == ["test.later"]
+
+
+def test_a_device_scalar_count_is_fetched_when_a_snapshot_reads_it():
+    t0 = _now()
+    spans.count("test.device", jnp.asarray(5, jnp.int32) + 2)
+    spans.count("test.host", 3)
+    (name, _, value), = [c for c in spans._counts if c[0] == "test.device"
+                         and c[1] >= t0]
+    assert isinstance(value, jax.Array)          # logged without a fetch
+    snap = spans.snapshot(t0)
+    assert _counted(snap, "test.device") == [7]
+    assert _counted(snap, "test.host") == [3]
+    assert all(type(v) is int for _, _, v in snap.counts)
 
 
 def test_a_span_that_raises_is_recorded():
@@ -116,6 +130,21 @@ def test_h2d_bytes_are_the_copied_arrays(reorder):
         sum(a.nbytes for a in copied)]
     (_, a, b), = _named(snap, "match.to_device")
     assert a < b
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_window_fallback_tiles_are_logged_as_counted(backend):
+    """The call logs its ``Counters.fallback_tiles`` as the device scalar
+    it is; the snapshot reads it back as the backends' one count."""
+    g = grid_graph(16, 16)
+    s = build_window_schedule(g, window=64, tile_size=32)
+    t0 = _now()
+    res = skipper_match(schedule=s, backend=backend)
+    (logged,) = [c[2] for c in spans._counts
+                 if c[0] == "match.window_fallback_tiles" and c[1] >= t0]
+    assert logged is res.counters.fallback_tiles
+    assert _counted(spans.snapshot(t0), "match.window_fallback_tiles") == [
+        int(skipper_match(schedule=s, backend="xla").counters.fallback_tiles)]
 
 
 # -- the named scopes ----------------------------------------------------------

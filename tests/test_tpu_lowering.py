@@ -2,11 +2,11 @@
 
 Interpret mode accepts block shapes, dtypes and matmuls that Mosaic refuses
 on the chip, so the interpret-mode suites cannot show that the kernels
-lower. These tests compile the window-tier pipeline kernel, the block-pair
-boundary kernel and the whole ``skipper_match`` pipeline jit with
-``interpret=False`` for a v5e that is described, not attached (the TPU
-compiler is installed with jax), at the real geometry W=2048, T=256, under
-both state specs. A kernel that Mosaic refuses fails here at no chip time.
+lower. These tests compile the window-tier pipeline kernel, the one-window
+kernel, the block-pair boundary kernel and the whole ``skipper_match``
+pipeline jit with ``interpret=False`` for a v5e that is described, not
+attached (the TPU compiler is installed with jax), at the real geometry
+W=2048, T=256, under both state specs. A kernel that Mosaic refuses fails here at no chip time.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports every
@@ -22,6 +22,7 @@ from repro.kernels.skipper_match import ops
 from repro.kernels.skipper_match.kernel import (
     build_boundary_matcher,
     build_pipeline_matcher,
+    build_window_matcher,
 )
 
 W, T = 2048, 256
@@ -75,6 +76,16 @@ def test_pipeline_kernel_lowers_for_v5e(spec, one_chip, no_compile_cache):
         ((NUM_ROWS, slots), jnp.int32), ((NUM_ROWS, slots), jnp.int32),
         ((NUM_ROWS, W), spec.vmem_dtype),
     )
+
+
+@pytest.mark.parametrize("spec", SPECS, ids=SPEC_IDS)
+def test_window_kernel_lowers_for_v5e(spec, one_chip, no_compile_cache):
+    """The one-window kernel shares the tile body and the SMEM fallback
+    count with the pipeline kernel."""
+    fn = build_window_matcher(TILES_PER_WINDOW, T, W, 1, True, False, spec)
+    n = TILES_PER_WINDOW * T
+    _compile(fn, one_chip, ((n,), jnp.int32), ((n,), jnp.int32),
+             ((W,), spec.vmem_dtype))
 
 
 @pytest.mark.parametrize("spec", SPECS, ids=SPEC_IDS)
