@@ -71,8 +71,13 @@ from repro.graphs.reorder import Reordering, reorder_vertices
 
 @dataclasses.dataclass(frozen=True)
 class WindowSchedule:
-    """Static-shape device schedule for one graph. All arrays are host numpy;
-    the driver moves them to device once, at trace time.
+    """Static-shape device schedule for one graph. All arrays are host numpy,
+    read-only when ``build_window_schedule`` made them. ``skipper_match``
+    copies them to the device on a schedule's first call and keeps that copy
+    resident for the next calls on the same schedule, until a call on
+    another schedule or until this one is collected: one schedule's copy at
+    a time lives in HBM. A schedule with writable arrays is copied on every
+    call.
 
     Consumed by the single-device pipeline (``kernels/skipper_match/ops``)
     and, via ``graphs/partition.partition_schedule``, by the
@@ -234,6 +239,9 @@ def build_window_schedule(
     Every phase but the reordering runs in a ``repro.spans`` span:
     ``schedule.canonical``, ``schedule.rows``, ``schedule.pairs`` and
     ``schedule.stream_map``, in that order.
+
+    Every numpy array of the result is read-only (the reordering's ``perm``
+    and ``inv`` too), which lets ``skipper_match`` keep its device copy.
     """
     n = edges.num_vertices
     with spans.span("schedule.canonical"):
@@ -380,7 +388,7 @@ def build_window_schedule(
         if nb:
             stream_src[bsel] = (slots_flat + slot_of).astype(np.int32)
 
-    return WindowSchedule(
+    schedule = WindowSchedule(
         window=window,
         tile_size=tile_size,
         num_windows=num_windows,
@@ -406,3 +414,9 @@ def build_window_schedule(
         num_windowed=int(windowed.sum()),
         stream_src=stream_src,
     )
+    # read-only, so that ``skipper_match`` may keep the device copy
+    for field in dataclasses.fields(schedule):
+        value = getattr(schedule, field.name)
+        if isinstance(value, np.ndarray):
+            value.setflags(write=False)
+    return schedule

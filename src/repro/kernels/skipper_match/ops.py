@@ -30,9 +30,11 @@ from typing import Optional, Tuple, Union
 
 import functools
 import re
+import weakref
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import spans
 from repro.core import engine
@@ -104,6 +106,64 @@ def _executable(fn, args):
     while len(_COMPILED) > 64:
         del _COMPILED[next(iter(_COMPILED))]
     return exe
+
+
+# The one schedule whose device copy outlives a call: (a weak reference to
+# the host schedule, the device it was copied to, the copied arguments).
+# Held here and not on the schedule, so that a caller that keeps many
+# schedules (a partitioner's coarsening levels) keeps one copy in HBM.
+_RESIDENT: Optional[tuple] = None
+
+
+def _drop_resident(ref=None) -> None:
+    """Forget the resident copy; given a weak reference (the callback of a
+    collected schedule), only if it is that schedule's."""
+    global _RESIDENT
+    if ref is None or (_RESIDENT is not None and _RESIDENT[0] is ref):
+        _RESIDENT = None
+
+
+def _immutable(a) -> bool:
+    """Whether nothing can write ``a`` in place: a ``jax.Array``, or a numpy
+    array that is read-only, as is every numpy array it is a view of."""
+    if isinstance(a, jax.Array):
+        return True
+    if not isinstance(a, np.ndarray):
+        return False
+    while isinstance(a, np.ndarray) and not a.flags.writeable:
+        a = a.base
+    return not isinstance(a, np.ndarray)
+
+
+def _schedule_args(schedule: WindowSchedule) -> Tuple[tuple, bool, int]:
+    """The pipeline's schedule arguments on the default device, landed;
+    whether they were resident; and the bytes copied from the host to put
+    them there (0 where they were resident).
+
+    Otherwise the resident copy is dropped before the new one is made, so
+    at most one schedule's copy is live between calls; the new copy stays
+    resident while its schedule lives, if every array of the schedule that
+    it holds is immutable (``build_window_schedule``'s are read-only)."""
+    global _RESIDENT
+    device = jax.config.jax_default_device or jax.local_devices()[0]
+    if isinstance(device, str):
+        device = jax.local_devices(backend=device)[0]
+    if (_RESIDENT is not None and _RESIDENT[0]() is schedule
+            and _RESIDENT[1] == device):
+        return _RESIDENT[2], True, 0
+    _RESIDENT = None
+    perm = schedule.perm
+    if perm is None:
+        perm = jnp.arange(schedule.num_vertices, dtype=jnp.int32)
+    host = (schedule.u_tiles, schedule.v_tiles, schedule.stream_src,
+            schedule.boundary_blk_u, schedule.boundary_blk_v,
+            schedule.boundary_ulocal, schedule.boundary_vlocal,
+            schedule.window_ids, perm)
+    args = jax.block_until_ready(jax.device_put(host))
+    if all(_immutable(h) for h in host):
+        _RESIDENT = (weakref.ref(schedule, _drop_resident), device, args)
+    return args, False, sum(d.nbytes for h, d in zip(host, args)
+                            if not isinstance(h, jax.Array))
 
 
 def _auto_interpret() -> bool:
@@ -343,10 +403,18 @@ def skipper_match(
     """Full-graph device-resident matcher: one traced pipeline for all
     windows plus the in-device boundary epilogue.
 
-    The call copies the schedule to the device and waits until the copy
-    has landed (span ``match.to_device``, counter ``match.h2d_bytes``),
-    then dispatches the pipeline, which runs asynchronously: a caller
-    cannot overlap host work with the copy. The window tier's count of
+    HBM lifetime of the schedule: at most one schedule's device copy
+    outlives a call. A call on the schedule whose copy is resident (the
+    same object, on the same default device) copies nothing and
+    dispatches the pipeline at once (counter ``match.schedule_resident``
+    1). Any other call drops the resident copy, copies its schedule and
+    waits until the copy has landed (span ``match.to_device``, counter
+    ``match.h2d_bytes``), then dispatches: a caller cannot overlap host
+    work with the copy. That copy stays resident until the next such call
+    or until its schedule is collected, and only if every numpy array it
+    was made from is read-only (``build_window_schedule`` marks them so);
+    a schedule with writable arrays is copied on every call. The pipeline
+    runs asynchronously. The window tier's count of
     tiles that took the exact fallback (``Counters.fallback_tiles``) is
     logged as the device scalar it is (counter
     ``match.window_fallback_tiles``), so the call does not wait for it.
@@ -433,19 +501,13 @@ def skipper_match(
         spec,
         *((True,) if with_conflicts else ()),
     )
-    perm = schedule.perm
-    if perm is None:
-        perm = jnp.arange(schedule.num_vertices, dtype=jnp.int32)
-    host = (schedule.u_tiles, schedule.v_tiles, schedule.stream_src,
-            schedule.boundary_blk_u, schedule.boundary_blk_v,
-            schedule.boundary_ulocal, schedule.boundary_vlocal,
-            schedule.window_ids, perm)
     # The schedule copy ends when the arrays have landed; the pipeline
-    # could not start before that anyway.
+    # could not start before that anyway. On the resident schedule the
+    # span is the lookup alone.
     with spans.span("match.to_device"):
-        args = jax.block_until_ready(jax.device_put(host))
-    spans.count("match.h2d_bytes", sum(
-        d.nbytes for h, d in zip(host, args) if not isinstance(h, jax.Array)))
+        args, resident, copied = _schedule_args(schedule)
+    spans.count("match.h2d_bytes", copied)
+    spans.count("match.schedule_resident", int(resident))
     mask, state, conflicts, counters = _executable(fn, args)(*args)
     spans.count("match.window_fallback_tiles", counters.fallback_tiles)
     result = MatchResult(match_mask=mask, state=state, counters=counters)
