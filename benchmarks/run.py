@@ -6,7 +6,6 @@
   fig10_gain        — Fig. 10/11: serial slowdown vs SGMM
   table2_conflicts  — Table II: JIT conflict statistics (+distributed)
   kernel_bench      — matcher/router throughput micro-benches
-  packing_bench     — matching-based sequence packing quality
 
 Run ``--scale large`` for the multi-million-edge suite (slower).
 """
@@ -35,7 +34,7 @@ def main() -> None:
     enable_compile_cache()
     from benchmarks import (
         table1_speedup, fig7_work, fig10_gain, table2_conflicts,
-        kernel_bench, packing_bench,
+        kernel_bench,
     )
 
     modules = {
@@ -44,7 +43,6 @@ def main() -> None:
         "fig10": fig10_gain,
         "table2": table2_conflicts,
         "kernels": kernel_bench,
-        "packing": packing_bench,
     }
     print("name,us_per_call,derived")
     failed = []

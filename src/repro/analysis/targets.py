@@ -99,19 +99,6 @@ def _trace_boundary_kernel(scale: int):
     return jax.make_jaxpr(call)(blk, blk, uv, uv, st)
 
 
-@functools.lru_cache(maxsize=None)
-def _trace_flash_attention(scale: int):
-    from repro.kernels.flash_attention.kernel import build_flash_attention
-
-    call = build_flash_attention(
-        batch=1, num_q_heads=2, num_kv_heads=1, seq_len=256,
-        head_dim=128, block_q=128, block_k=128, interpret=True,
-    )
-    q = jax.ShapeDtypeStruct((1, 2, 256, 128), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((1, 1, 256, 128), jnp.bfloat16)
-    return jax.make_jaxpr(call)(q, kv, kv)
-
-
 # --------------------------------------------------------------------------
 # entry-point targets
 # --------------------------------------------------------------------------
@@ -254,12 +241,6 @@ TARGETS: Dict[str, Target] = {
             rescalable=True,
             vmem_claim="O(window + tile^2), independent of V "
                        "(DESIGN.md §10: (2, W) pair scratch, ANY state)",
-        ),
-        Target(
-            name="flash_attention",
-            build=_trace_flash_attention,
-            expect_pallas=1,
-            vmem_claim="O(block_q * d + S * d) per (batch, head) step",
         ),
         Target(
             name="skipper_match_pallas",

@@ -1,6 +1,6 @@
 """Capacity-constrained bipartite b-matching — the Skipper technique applied
-to MoE token-expert assignment (first-class framework integration, DESIGN.md
-§3, §9).
+to MoE token-expert assignment, as a standalone entry point
+(``bmatch_assign``; DESIGN.md §3, §9).
 
 Problem: tokens x experts, candidate edges (t, e) with router scores; each
 token may take at most ``token_budget`` experts, each expert at most
@@ -42,8 +42,8 @@ from repro.core.statespec import StateSpec, resolve as resolve_spec
 # is common rather than Θ(λ²)-rare. Round 1 commits each vertex's first
 # `room` claims; round 2 retires the cross-side chains that round 1's
 # commits unblock (see DESIGN.md §9). With vector_rounds=1 those chains fall
-# into the while_loop fallback, which under vmap (the MoE router vmaps
-# groups) costs every group the batch-max iteration count;
+# into the while_loop fallback, which under a caller's vmap over token
+# groups costs every group the batch-max iteration count;
 # tests/test_bipartite.py::test_rounds_sensitivity pins both the invariance
 # and the round-2 economics.
 BMATCH_VECTOR_ROUNDS = 2

@@ -1,9 +1,8 @@
-"""Pallas TPU kernels for the perf-critical compute layers.
+"""Pallas TPU kernels for the matcher's hot loop.
 
 skipper_match/    — the paper's hot loop: windowed single-pass greedy matching
-flash_attention/  — causal/GQA/sliding-window attention for the LM substrate
 
-Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
+The package ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper), ref.py (pure-jnp oracle). Validated with interpret=True on CPU;
 BlockSpecs are written for TPU VMEM tiling (see docstrings).
 """

@@ -1,18 +1,3 @@
-from repro.roofline.analysis import (
-    RooflineTerms,
-    analyze,
-    collective_bytes,
-    model_flops,
-    PEAK_FLOPS,
-    HBM_BW,
-    LINK_BW,
-    HBM_PER_CHIP,
-    state_traffic_bytes,
-    vmem_step_bytes,
-)
+from repro.roofline.analysis import state_traffic_bytes, vmem_step_bytes
 
-__all__ = [
-    "RooflineTerms", "analyze", "collective_bytes", "model_flops",
-    "PEAK_FLOPS", "HBM_BW", "LINK_BW", "HBM_PER_CHIP",
-    "state_traffic_bytes", "vmem_step_bytes",
-]
+__all__ = ["state_traffic_bytes", "vmem_step_bytes"]

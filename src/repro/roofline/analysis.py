@@ -1,183 +1,18 @@
-"""Roofline term derivation from compiled dry-run artifacts.
+"""Byte budgets of the matcher, for roofline studies.
 
-For each (arch x shape x mesh) the dry-run records:
-  compute term    = HLO_FLOPs_per_device / peak_FLOPs
-  memory term     = HLO_bytes_per_device / HBM_bw
-  collective term = collective_bytes_per_device / link_bw
+:func:`state_traffic_bytes` turns a run's state-access counters into HBM
+bytes under a state spec; :func:`vmem_step_bytes` reads a traced kernel's
+per-grid-step VMEM budget from the conformance analyzer. The benchmark's
+kernel rooflines are computed from the device trace in
+``bench/metrics/tier_bytes.py``, not here.
 
-cost_analysis() on an SPMD-partitioned executable reports *per-device*
-numbers (verified empirically in tests/test_roofline.py), so no chip division
-is applied to them; collective bytes are parsed from the partitioned HLO —
-also per-device — by summing operand bytes of every all-gather / all-reduce /
-reduce-scatter / all-to-all / collective-permute (i.e. the spec's
-"collective_bytes / (chips x link_bw)" with both sides already per-chip).
-
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (we use 1 link; multi-link meshes only improve the term).
+Hardware constant: TPU v5e HBM, 819 GB/s.
 """
 from __future__ import annotations
 
-import dataclasses
-import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict
 
-PEAK_FLOPS = 197e12          # bf16 / chip
 HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / link
-HBM_PER_CHIP = 16 * 1024**3  # v5e: 16 GiB
-
-_DTYPE_BYTES = {
-    "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
-    "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,
-    "token": 0, "opaque": 0,
-}
-
-_COLLECTIVES = (
-    "all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-    "collective-permute", "collective-broadcast",
-)
-
-_SHAPE_RE = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
-
-
-def _shape_bytes(dtype: str, dims: str) -> int:
-    nbytes = _DTYPE_BYTES.get(dtype)
-    if nbytes is None:
-        return 0
-    n = 1
-    if dims:
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-    return n * nbytes
-
-
-def collective_bytes(hlo_text: str) -> Dict[str, int]:
-    """Per-collective-kind operand bytes summed over the (per-device) module.
-
-    HLO line shape: ``%x = TYPE op-name(operands...)`` — the first
-    dtype[shape] token is the result; operand shapes are parsed from inside
-    the call parens when present, else we fall back to the result size (for
-    all-reduce operand == result anyway).
-    """
-    out: Dict[str, int] = {k: 0 for k in _COLLECTIVES}
-    for line in hlo_text.splitlines():
-        stripped = line.strip()
-        m = re.search(r"=\s*(?:\([^)]*\)|\S+)\s+([a-z\-]+)(?:-start|-done)?\(", stripped)
-        if not m:
-            continue
-        op = m.group(1)
-        base = op.replace("-start", "").replace("-done", "")
-        if base not in _COLLECTIVES:
-            continue
-        if op.endswith("-done"):
-            continue  # counted at -start
-        # operands: shapes appearing after the opening paren of the op call
-        call_idx = stripped.find(base + "(")
-        if call_idx == -1:
-            call_idx = stripped.find("(")
-        operand_str = stripped[call_idx:]
-        shapes = _SHAPE_RE.findall(operand_str)
-        if not shapes:
-            shapes = _SHAPE_RE.findall(stripped)[:1]
-        total = sum(_shape_bytes(d, s) for d, s in shapes)
-        out[base] += total
-    return out
-
-
-@dataclasses.dataclass
-class RooflineTerms:
-    flops: float                 # per device
-    hbm_bytes: float             # per device
-    coll_bytes: float            # per device
-    coll_breakdown: Dict[str, int]
-    bytes_per_device: int        # resident (args + temps)
-    compute_s: float
-    memory_s: float
-    collective_s: float
-    cpu_convert_artifact: int = 0   # bf16->f32 dot-emulation buffers (absent on TPU)
-
-    @property
-    def dominant(self) -> str:
-        terms = {
-            "compute": self.compute_s,
-            "memory": self.memory_s,
-            "collective": self.collective_s,
-        }
-        return max(terms, key=terms.get)
-
-    @property
-    def step_time_s(self) -> float:
-        """Roofline step-time model: dominant term (perfect overlap bound)."""
-        return max(self.compute_s, self.memory_s, self.collective_s)
-
-    def to_dict(self) -> Dict:
-        return {
-            "flops_per_device": self.flops,
-            "hbm_bytes_per_device": self.hbm_bytes,
-            "collective_bytes_per_device": self.coll_bytes,
-            "collective_breakdown": self.coll_breakdown,
-            "bytes_per_device": self.bytes_per_device,
-            "cpu_convert_artifact_bytes": self.cpu_convert_artifact,
-            "bytes_per_device_tpu_corrected": self.bytes_per_device - self.cpu_convert_artifact,
-            "compute_s": self.compute_s,
-            "memory_s": self.memory_s,
-            "collective_s": self.collective_s,
-            "dominant": self.dominant,
-            "step_time_s": self.step_time_s,
-        }
-
-
-def analyze(compiled, hlo_text: Optional[str] = None) -> RooflineTerms:
-    """Terms from the trip-count-corrected structural HLO parse
-    (roofline/hlo_parse.py). Raw cost_analysis() is NOT usable directly: XLA
-    visits while (lax.scan) bodies once, undercounting layer-scanned models
-    by ~num_layers x (verified in tests/test_roofline.py)."""
-    from repro.roofline.hlo_parse import analyze_hlo
-
-    text = hlo_text if hlo_text is not None else compiled.as_text()
-    costs = analyze_hlo(text)
-    flops = costs.dot_flops
-    hbm = costs.traffic_bytes
-    coll_total = costs.collective_bytes
-    ma = compiled.memory_analysis()
-    resident = int(
-        ma.argument_size_in_bytes + ma.output_size_in_bytes
-        + ma.temp_size_in_bytes - ma.alias_size_in_bytes
-    )
-    # XLA:CPU emulates bf16 dots by promoting operands to f32; the hoisted
-    # convert buffers (absent on TPU, where the MXU consumes bf16 natively)
-    # inflate temp memory. Quantify them so the fits-HBM check can report a
-    # TPU-corrected resident size alongside the raw one.
-    artifact = _cpu_convert_artifact_bytes(text)
-    return RooflineTerms(
-        flops=flops,
-        hbm_bytes=hbm,
-        coll_bytes=coll_total,
-        coll_breakdown={k: int(v) for k, v in costs.collective_breakdown.items() if v},
-        bytes_per_device=resident,
-        cpu_convert_artifact=artifact,
-        compute_s=flops / PEAK_FLOPS,
-        memory_s=hbm / HBM_BW,
-        collective_s=coll_total / LINK_BW,
-    )
-
-
-_CONVERT_RE = re.compile(
-    r"=\s*f32\[([\d,]+)\][^=]*fusion\([^)]*\),\s*kind=kLoop,"
-    r"\s*calls=%wrapped_convert"
-)
-
-
-def _cpu_convert_artifact_bytes(text: str) -> int:
-    total = 0
-    for m in _CONVERT_RE.finditer(text):
-        n = 1
-        for d in m.group(1).split(","):
-            if d:
-                n *= int(d)
-        total += 4 * n
-    return total
 
 
 def state_traffic_bytes(counters, spec=None) -> Dict[str, float]:
@@ -210,18 +45,6 @@ def state_traffic_bytes(counters, spec=None) -> Dict[str, float]:
     }
 
 
-def model_flops(cfg, shape, n_params_active: int, n_params_total: int) -> float:
-    """MODEL_FLOPS = 6*N*D for training, 2*N*tokens for inference."""
-    if shape.kind == "train":
-        tokens = shape.global_batch * shape.seq_len
-        return 6.0 * n_params_active * tokens
-    if shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        return 2.0 * n_params_active * tokens
-    # decode: one token per sequence
-    return 2.0 * n_params_active * shape.global_batch
-
-
 def vmem_step_bytes(target: str = "boundary_kernel") -> Dict[str, Dict]:
     """Per-grid-step VMEM byte budget of a traced kernel target, keyed by
     kernel name — the static companion to :func:`state_traffic_bytes`.
@@ -232,7 +55,7 @@ def vmem_step_bytes(target: str = "boundary_kernel") -> Dict[str, Dict]:
     upper bound on intermediates — the same numbers the ``vmem-budget``
     rule gates in CI, surfaced here so roofline studies can quote them.
     Targets: see ``repro.analysis.targets.TARGETS`` (e.g.
-    ``boundary_kernel``, ``pipeline_kernel``, ``flash_attention``).
+    ``boundary_kernel``, ``pipeline_kernel``, ``window_kernel``).
     """
     from repro.analysis.rules.vmem_budget import kernel_step_bytes
     from repro.analysis.targets import get_targets

@@ -18,7 +18,7 @@ each file stops growing its own slightly-different ``_graph`` helper:
 * :func:`adversarial_edge_list` — the contention mix the fuzzer uses
   (hub fan-in + chain runs + duplicates + self-loops + padding).
 * :func:`random_candidate_stream` — b-matching candidate streams with
-  invalid slots, for the bipartite/MoE suites.
+  invalid slots, for the APRAM suite.
 * :func:`run_subprocess` — run a script under
   ``--xla_force_host_platform_device_count=N`` (moved here from
   test_distributed so the faults/statespec/apram suites stop importing a

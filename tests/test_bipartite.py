@@ -1,5 +1,5 @@
-"""The capacitated first-K-claim engine (DESIGN.md §9) and the MoE b-matching
-router built on it.
+"""The capacitated first-K-claim engine (DESIGN.md §9) and the b-matching
+token-to-expert assignment built on it (``bmatch_assign``).
 
 Pins the three contracts the PR-4 unification relies on:
   * bmatch_assign == the sequential greedy over the score-sorted stream
@@ -247,3 +247,48 @@ def test_used_counts_cross_tiles():
         expert_capacity=2, tile_size=2,   # two tiles of two edges
     )
     assert np.asarray(accept).tolist() == [True, True, False, True]
+
+
+@settings(max_examples=25, deadline=None)
+@given(
+    n_tok=st.integers(1, 200),
+    n_exp=st.integers(1, 16),
+    budget=st.integers(1, 4),
+    cap=st.integers(1, 64),
+    seed=st.integers(0, 2**31 - 1),
+)
+def test_property_bmatch_capacities(n_tok, n_exp, budget, cap, seed):
+    """Invariants: per-token budget and per-expert capacity are never
+    violated; the assignment is maximal (no acceptable edge remains)."""
+    rng = np.random.default_rng(seed)
+    m = n_tok * min(n_exp, budget + 2)
+    tok = rng.integers(0, n_tok, m).astype(np.int32)
+    exp = rng.integers(0, n_exp, m).astype(np.int32)
+    accept = np.asarray(
+        bmatch_assign(
+            jnp.asarray(tok), jnp.asarray(exp),
+            num_tokens=n_tok, num_experts=n_exp,
+            token_budget=budget, expert_capacity=cap, tile_size=64,
+        )
+    )
+    tok_used = np.bincount(tok[accept], minlength=n_tok)
+    exp_used = np.bincount(exp[accept], minlength=n_exp)
+    assert tok_used.max(initial=0) <= budget
+    assert exp_used.max(initial=0) <= cap
+    # maximality: every rejected edge was blocked by a full token or expert
+    # *at its decision point*; at the end, any edge with BOTH sides free would
+    # violate maximality.
+    for t, e, a in zip(tok, exp, accept):
+        if not a:
+            assert tok_used[t] >= budget or exp_used[e] >= cap
+
+
+def test_bmatch_respects_priority_order():
+    """Earlier (higher-score) edges win contested capacity."""
+    tok = jnp.asarray([0, 1, 2], jnp.int32)
+    exp = jnp.asarray([0, 0, 0], jnp.int32)
+    accept = bmatch_assign(
+        tok, exp, num_tokens=3, num_experts=1,
+        token_budget=1, expert_capacity=2, tile_size=64,
+    )
+    assert accept.tolist() == [True, True, False]

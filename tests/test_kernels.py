@@ -2,7 +2,6 @@
 pure-jnp oracles, per the kernel contract (kernel.py + ops.py + ref.py)."""
 import numpy as np
 import pytest
-import jax
 import jax.numpy as jnp
 
 from repro.graphs import erdos_renyi_graph, grid_graph, rmat_graph
@@ -10,7 +9,6 @@ from repro.core import assert_matching, sgmm
 from repro.kernels.skipper_match import (
     skipper_match, skipper_match_window, ref_match_window,
 )
-from repro.kernels.flash_attention import flash_attention, ref_attention
 
 
 # ------------------------------------------------------------ skipper ------
@@ -123,49 +121,3 @@ def test_skipper_kernel_empty_and_selfloops():
     res = skipper_match(g, window=16, tile_size=64)
     assert int(res.match_mask.sum()) == 0
 
-
-# ------------------------------------------------------ flash attention ----
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)])
-@pytest.mark.parametrize("b,hq,hkv,s,d", [
-    (2, 4, 2, 256, 64),
-    (1, 8, 1, 256, 128),
-    (2, 4, 4, 128, 64),
-])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_sweep(dtype, tol, b, hq, hkv, s, d, causal):
-    key = jax.random.PRNGKey(b * 17 + s)
-    kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (b, hq, s, d), dtype)
-    k = jax.random.normal(kk, (b, hkv, s, d), dtype)
-    v = jax.random.normal(kv, (b, hkv, s, d), dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
-    ref = ref_attention(q, k, v, causal=causal)
-    err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
-    assert err < tol, err
-
-
-@pytest.mark.parametrize("window", [64, 128])
-def test_flash_attention_sliding_window(window):
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (1, 4, 512, 64), jnp.float32)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (1, 2, 512, 64), jnp.float32)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (1, 2, 512, 64), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, window=window, block_q=64, block_k=64)
-    ref = ref_attention(q, k, v, causal=True, window=window)
-    assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
-
-
-def test_flash_attention_matches_model_attention():
-    """Cross-validate the kernel against the model-side chunked attention."""
-    from repro.models.layers import gqa_attention_chunked
-    key = jax.random.PRNGKey(3)
-    b, hq, hkv, s, d = 2, 8, 2, 256, 64
-    q = jax.random.normal(key, (b, s, hq, d), jnp.float32)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, hkv, d), jnp.float32)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, hkv, d), jnp.float32)
-    model_out = gqa_attention_chunked(q, k, v, causal=True, q_chunk=128, kv_chunk=64)
-    kern_out = flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-        causal=True, block_q=64, block_k=64,
-    ).transpose(0, 2, 1, 3)
-    assert float(jnp.max(jnp.abs(model_out - kern_out))) < 1e-4
